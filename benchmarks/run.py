@@ -28,6 +28,7 @@ from benchmarks import (bench_ablation, bench_batch_latency, bench_decode,
                         bench_memory, bench_memory_alloc, bench_online,
                         bench_overhead, bench_placement, bench_simperf,
                         bench_throughput, bench_kernels)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import log as obslog
 
 log = obslog.get_logger("bench")
@@ -183,6 +184,7 @@ def main(argv=None):
 
     obslog.set_level(obslog.level_from_flags(quiet=args.quiet,
                                              verbose=args.verbose))
+    enable_compile_cache()
     validate_registry()
     keys = args.only.split(",") if args.only else list(SUITES)
     unknown = [k for k in keys if k not in SUITES]

@@ -8,6 +8,7 @@ Chained dependency: every draft expert's output is verified by a shared
   PYTHONPATH=src python examples/lm_coe_router.py
 """
 import dataclasses
+import tempfile
 import time
 
 import jax
@@ -35,14 +36,15 @@ def lm_apply(params, tokens):
 
 
 def main():
-    store = HostStore(root="/tmp/lm_coe_store")
+    store = HostStore(root=tempfile.mkdtemp(prefix="lm_coe_"))
     payload = {
         "make_batch": lambda reqs: np.stack([r.data["tokens"] for r in reqs]),
         "interpret": lambda out: ["ok" if int(t) % 7 else "flag" for t in out],
     }
-    mem = sum(int(np.prod(p.shape)) * 4 for p in jax.tree.leaves(
-        transformer.init_params(jax.random.PRNGKey(0), cfg)))
+    mem = sum(p.size * p.dtype.itemsize
+              for p in jax.tree.leaves(transformer.abstract_params(cfg)))
 
+    # the store copies each expert's weights to host memory (NumPy)
     experts = []
     for i, dom in enumerate(DOMAINS):           # one fine-tune per domain
         params = transformer.init_params(jax.random.PRNGKey(i), cfg)

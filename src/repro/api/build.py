@@ -339,17 +339,23 @@ def build_real_system(n_components: int = 24, n_detection: int = 4,
     sample = _tiny_params(jax.random.PRNGKey(9), 64, d_hidden, 2)
 
     # CPU service-time line, measured with the same runner pinned to the
-    # host backend (paper §4.1's heterogeneous serving premise)
-    cpu_dev = jax.devices("cpu")[0]
-    cpu_sample = jax.device_put(sample, cpu_dev)
+    # host backend (paper §4.1's heterogeneous serving premise). This
+    # profile is the only user of the CPU backend; where the process has
+    # none (JAX_PLATFORMS=tpu) the profile carries no CPU line.
+    try:
+        cpu_dev = jax.devices("cpu")[0]
+    except RuntimeError:
+        run_batch_cpu = None
+    else:
+        cpu_sample = jax.device_put(sample, cpu_dev)
 
-    def run_batch_cpu(n: int) -> float:
-        x = jax.device_put(np.zeros((n, 64), np.float32), cpu_dev)
-        fn = apply_fns["tiny_cls"]
-        fn(cpu_sample, x)  # warm
-        t0 = _t.perf_counter()
-        jax.block_until_ready(fn(cpu_sample, x))
-        return _t.perf_counter() - t0
+        def run_batch_cpu(n: int) -> float:
+            x = jax.device_put(np.zeros((n, 64), np.float32), cpu_dev)
+            fn = apply_fns["tiny_cls"]
+            fn(cpu_sample, x)  # warm
+            t0 = _t.perf_counter()
+            jax.block_until_ready(fn(cpu_sample, x))
+            return _t.perf_counter() - t0
 
     prof = microbenchmark_arch("tiny_cls", run_batch_factory(sample), mem,
                                act_bytes_per_item=64 * 4, tier=tier,

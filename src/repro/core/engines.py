@@ -2,9 +2,9 @@
 
 ``SimEngine`` — latencies from offline profiles + the unified memory
 hierarchy (``repro.memory``); drives the event-driven simulator at the
-paper's scale (hundreds of experts) on this CPU-only box. Every transfer it
-performs occupies the hierarchy's *shared* SSD/PCIe channels, so concurrent
-loads contend instead of each pretending it owns the link.
+paper's scale (hundreds of experts) without touching a device. Every
+transfer it performs occupies the hierarchy's *shared* SSD/PCIe channels, so
+concurrent loads contend instead of each pretending it owns the link.
 
 ``RealEngine`` — actually loads JAX expert params across host/disk tiers and
 runs jitted forwards, measuring wall time. Loads queue on real transfer
@@ -87,9 +87,10 @@ class RingKVCache:
     Host-side numpy rings in the heads-major layout ``slot_cache_shape``
     emits ([Hkv, W, D]); ``append`` writes slot ``pos % width`` (the ring
     update), ``attend`` runs the Pallas ``decode_attention`` kernel over
-    the ring (interpret mode on this CPU-only box). Positions past
-    ``width`` overwrite the oldest slot — the kernel's validity mask
-    reconstructs absolute positions from the scalar ``pos``.
+    the ring through ``decode_attention_op`` (native on TPU, interpreted
+    on other backends). Positions past ``width`` overwrite the oldest slot
+    — the kernel's validity mask reconstructs absolute positions from the
+    scalar ``pos``.
     """
 
     def __init__(self, num_heads: int = 4, num_kv_heads: int = 2,
@@ -126,11 +127,10 @@ class RingKVCache:
         they cannot share a batched call)."""
         import jax.numpy as jnp
 
-        from repro.kernels.decode_attention import decode_attention
-        out = decode_attention(
+        from repro.kernels.ops import decode_attention_op
+        out = decode_attention_op(
             jnp.asarray(q)[None], jnp.asarray(self.k)[None],
-            jnp.asarray(self.v)[None], self.pos,
-            window=self.window, interpret=True)
+            jnp.asarray(self.v)[None], self.pos, window=self.window)
         return np.asarray(out[0])
 
 
@@ -139,27 +139,34 @@ class HostStore:
 
     Experts start on 'disk' (.npz files) or in host memory; loads into an
     executor deserialize + ``jax.device_put`` the pytree — the real analogue
-    of the paper's SSD -> DRAM -> GPU expert switching.
+    of the paper's SSD -> DRAM -> GPU expert switching. The host tier holds
+    NumPy arrays only: ``put_host`` copies device arrays to host memory, so
+    a host -> device load is one transfer and the host tier never occupies
+    device memory.
     """
 
     def __init__(self, root: Optional[str] = None):
         self.host: Dict[str, Any] = {}
         self.disk: Dict[str, str] = {}
         self.root = root
+        self._disk_layout: Dict[str, Tuple[Any, list]] = {}
 
     def put_host(self, expert_id: str, params: Any):
-        self.host[expert_id] = params
+        import jax
+        self.host[expert_id] = jax.device_get(params)
 
     def put_disk(self, expert_id: str, params: Any):
         import jax
         assert self.root, "HostStore needs a root dir for disk tier"
         os.makedirs(self.root, exist_ok=True)
         path = os.path.join(self.root, f"{expert_id}.npz")
-        leaves, treedef = jax.tree.flatten(params)
-        np.savez(path, *[np.asarray(l) for l in leaves])
+        leaves, treedef = jax.tree.flatten(jax.device_get(params))
+        leaves = [np.asarray(l) for l in leaves]
+        np.savez(path, *leaves)
         self.disk[expert_id] = path
-        self._treedefs = getattr(self, "_treedefs", {})
-        self._treedefs[expert_id] = treedef
+        # .npz keeps no extension dtypes (bfloat16 reads back as raw bytes):
+        # the dtypes are restored by view on fetch
+        self._disk_layout[expert_id] = (treedef, [l.dtype for l in leaves])
 
     def fetch(self, expert_id: str) -> Tuple[Any, str]:
         """Returns (host-side params, source tier)."""
@@ -167,9 +174,10 @@ class HostStore:
         if expert_id in self.host:
             return self.host[expert_id], "host"
         path = self.disk[expert_id]
+        treedef, dtypes = self._disk_layout[expert_id]
         with np.load(path) as z:
-            leaves = [z[k] for k in z.files]
-        params = jax.tree.unflatten(self._treedefs[expert_id], leaves)
+            leaves = [z[k].view(dt) for k, dt in zip(z.files, dtypes)]
+        params = jax.tree.unflatten(treedef, leaves)
         self.host[expert_id] = params          # disk read populates host cache
         return params, "disk"
 
@@ -308,8 +316,7 @@ class RealEngine:
         import jax
         t0 = time.perf_counter()
         host_params, _ = self.store.fetch(expert_id)
-        dev = jax.tree.map(lambda a: jax.device_put(np.asarray(a)), host_params)
-        jax.block_until_ready(jax.tree.leaves(dev))
+        dev = jax.block_until_ready(jax.device_put(host_params))
         with self._lock:
             self.device_params[expert_id] = dev
             if timed:
@@ -336,9 +343,17 @@ class RealEngine:
             _TransferWorker.wait(handle)
 
     def unload(self, ex, expert_id: str) -> None:
+        """Evict: delete the expert's device buffers now, so the incoming
+        load lands in freed memory (the device peak is the pool plus the
+        working set, never pool plus one expert). Host-executed params are
+        the host store's NumPy arrays and stay."""
+        import jax
         self.wait_load(ex, expert_id)    # never drop a half-landed transfer
         with self._lock:
-            self.device_params.pop(expert_id, None)
+            params = self.device_params.pop(expert_id, None)
+        for leaf in jax.tree.leaves(params):
+            if isinstance(leaf, jax.Array):
+                leaf.delete()
 
     def warm_place(self, pool, expert_id: str) -> None:
         """Initial placement (system-init phase): transfer without timing."""

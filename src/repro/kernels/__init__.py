@@ -5,7 +5,9 @@
 - mamba_scan: chunked selective scan for the SSM/hybrid architectures
 
 Each kernel is a ``pl.pallas_call`` with explicit BlockSpec VMEM tiling,
-validated in interpret mode against ``ref.py`` across shape/dtype sweeps.
+validated in interpret mode against ``ref.py`` across shape/dtype sweeps
+and compiled for a described TPU v5e chip (``tests/test_tpu_compile.py``).
+``ops.interpret_mode()`` picks native or interpreted execution.
 """
 from repro.kernels.ops import (decode_attention_op, flash_attention_op,
                                mamba_scan_op)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -64,7 +62,7 @@ def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(
     jax.jit, static_argnames=("window", "block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
-                     block_k: int = 256, interpret: bool = True):
+                     block_k: int = 256, interpret: bool):
     """q: [B,H,D]; caches: [B,Hkv,W,D]; pos: scalar int32 -> [B,H,D]."""
     b, h, d = q.shape
     hkv, w = k_cache.shape[1], k_cache.shape[2]
@@ -102,7 +100,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, qf, kf, vf)

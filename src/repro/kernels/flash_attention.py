@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -83,7 +81,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: [B,H,S,D]; k,v: [B,Hkv,T,D] -> [B,H,S,D] (GQA via head grouping)."""
     b, h, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]
@@ -129,7 +127,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

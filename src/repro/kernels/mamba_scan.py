@@ -20,38 +20,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hout_ref,
-                 h_ref, *, block_s, seq_len, n_chunks):
+                 h_ref, x_s, dt_s, b_s, c_s, y_s, *, block_s, seq_len,
+                 n_chunks):
     sj = pl.program_id(2)
 
     @pl.when(sj == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)          # [bs, bd]
-    dt = dt_ref[0].astype(jnp.float32)        # [bs, bd]
-    bm = b_ref[0].astype(jnp.float32)         # [bs, N]
-    cm = c_ref[0].astype(jnp.float32)         # [bs, N]
-    a = a_ref[...].astype(jnp.float32)        # [bd, N]
-    d_vec = d_ref[...].astype(jnp.float32)    # [1, bd]
+    # f32 copies of the chunk; each timestep reads its row from these refs
+    # with ``pl.ds(t, 1)`` (Mosaic lowers no dynamic_slice of a loaded
+    # value, and single-row slices are tile-aligned only for 32-bit data)
+    x_s[...] = x_ref[0].astype(jnp.float32)      # [bs, bd]
+    dt_s[...] = dt_ref[0].astype(jnp.float32)    # [bs, bd]
+    b_s[...] = b_ref[0].astype(jnp.float32)      # [bs, N]
+    c_s[...] = c_ref[0].astype(jnp.float32)      # [bs, N]
+    a = a_ref[...].astype(jnp.float32)           # [bd, N]
 
-    def step(t, carry):
-        h, y = carry
-        da = jnp.exp(dt[t][:, None] * a)                  # [bd, N]
-        dbx = (dt[t] * x[t])[:, None] * bm[t][None, :]    # [bd, N]
+    def step(t, h):
+        row = pl.ds(t, 1)
+        dt_t = dt_s[row, :]                                       # [1, bd]
+        da = jnp.exp(dt_t.reshape(-1, 1) * a)                     # [bd, N]
+        dbx = (dt_t * x_s[row, :]).reshape(-1, 1) * b_s[row, :]   # [bd, N]
         h = da * h + dbx
-        y_t = jnp.sum(h * cm[t][None, :], axis=1)         # [bd]
-        y = jax.lax.dynamic_update_slice_in_dim(y, y_t[None], t, axis=0)
-        return h, y
+        y_s[row, :] = jnp.sum(h * c_s[row, :], axis=1).reshape(1, -1)
+        return h
 
-    h0 = h_ref[...]
-    y0 = jnp.zeros((block_s, x.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, block_s, step, (h0, y0))
-    h_ref[...] = h
-    y_ref[0] = (y + x * d_vec).astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, block_s, step, h_ref[...])
+    d_vec = d_ref[...].astype(jnp.float32)       # [1, bd]
+    y_ref[0] = (y_s[...] + x_s[...] * d_vec).astype(y_ref.dtype)
 
     @pl.when(sj == n_chunks - 1)
     def _emit_state():
@@ -61,7 +60,7 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hout_ref,
 @functools.partial(
     jax.jit, static_argnames=("block_d", "block_s", "interpret"))
 def mamba_scan(x, dt, b_mat, c_mat, a, d_vec, *, block_d: int = 128,
-               block_s: int = 128, interpret: bool = True):
+               block_s: int = 128, interpret: bool):
     """x, dt: [B,S,D]; b_mat, c_mat: [B,S,N]; a: [D,N]; d_vec: [D].
     Returns (y [B,S,D], h_final [B,D,N])."""
     bsz, s, d = x.shape
@@ -101,8 +100,13 @@ def mamba_scan(x, dt, b_mat, c_mat, a, d_vec, *, block_d: int = 128,
             jax.ShapeDtypeStruct((bsz, s + s_pad, d), x.dtype),
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32),      # h
+                        pltpu.VMEM((block_s, block_d), jnp.float32),  # x
+                        pltpu.VMEM((block_s, block_d), jnp.float32),  # dt
+                        pltpu.VMEM((block_s, n), jnp.float32),        # B
+                        pltpu.VMEM((block_s, n), jnp.float32),        # C
+                        pltpu.VMEM((block_s, block_d), jnp.float32)],  # y
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b_mat, c_mat, a, d_vec.reshape(1, d))

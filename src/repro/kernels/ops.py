@@ -1,13 +1,14 @@
 """Jit'd kernel entry points with backend selection.
 
-On TPU the Pallas kernels lower natively; elsewhere (this CPU container) they
-run in ``interpret=True`` mode. ``impl="xla"`` falls back to the pure-jnp
-reference (used by the dry-run, where only XLA ops lower for the host
-platform). Models call these through ``cfg.attn_impl``.
+Source of truth for kernel dispatch: ``interpret_mode()`` is the one place
+that decides how a Pallas kernel runs. On TPU the kernels lower natively
+(``tpu_custom_call``); on any other backend (the CPU test runs) they run in
+the Pallas interpreter. The kernels themselves take ``interpret`` as a
+required argument, so every call either goes through here or states its
+mode. ``impl="xla"`` selects the pure-jnp reference instead. Models call
+these through ``cfg.attn_impl``.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 
@@ -17,7 +18,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """True when the Pallas kernels must run interpreted (no TPU backend)."""
     return jax.default_backend() != "tpu"
 
 
@@ -28,7 +30,7 @@ def flash_attention_op(q, k, v, *, causal=True, window=0, impl="pallas",
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
 
 
 def decode_attention_op(q, k_cache, v_cache, pos, *, window=0, impl="pallas",
@@ -38,7 +40,7 @@ def decode_attention_op(q, k_cache, v_cache, pos, *, window=0, impl="pallas",
         return ref.decode_attention_ref(q, k_cache, v_cache, pos,
                                         window=window)
     return decode_attention(q, k_cache, v_cache, pos, window=window,
-                            block_k=block_k, interpret=_interpret())
+                            block_k=block_k, interpret=interpret_mode())
 
 
 def mamba_scan_op(x, dt, b_mat, c_mat, a, d_vec, *, impl="pallas",
@@ -48,4 +50,4 @@ def mamba_scan_op(x, dt, b_mat, c_mat, a, d_vec, *, impl="pallas",
         return ref.mamba_scan_ref(x, dt, b_mat, c_mat, a, d_vec)
     return mamba_scan(x, dt, b_mat, c_mat, a, d_vec,
                       block_d=block_d, block_s=block_s,
-                      interpret=_interpret())
+                      interpret=interpret_mode())

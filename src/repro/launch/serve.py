@@ -8,7 +8,8 @@ Three modes behind the SAME scheduler/manager code:
   --mode sim     paper-scale circuit-board workload (352 experts, 2500+ reqs)
                  on the event-driven engine — reproduces the paper's numbers.
   --mode real    actually loads JAX expert params across host/disk tiers and
-                 runs jitted forwards on the local device.
+                 runs jitted forwards on the default JAX device (one TPU
+                 chip, or the CPU under JAX_PLATFORMS=cpu).
   --mode online  streaming multi-tenant front-end (repro.serve): generator
                  arrivals, per-tenant SLO telemetry, admission control and
                  autoscaling (``--engine real`` for real JAX experts).
@@ -46,6 +47,7 @@ from repro.api.build import real_board_layout as _real_board_layout  # noqa: F40
 from repro.api.spec import (DecodeSection, FleetSection, HeteroSection,
                             MemorySection, ModelSpec, PolicySection,
                             ServingSection, TenantSection, WorkloadSection)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memory import POLICY_NAMES
 from repro.obs import log as obslog
 
@@ -466,6 +468,7 @@ def main(argv=None):
 
     log.debug(f"mode={spec.serving.mode} engine={spec.serving.engine} "
               f"policy={spec.policy.name} requests={spec.workload.requests}")
+    enable_compile_cache()
     try:
         sess = Session(spec)
     except (SpecError, ValueError) as e:
