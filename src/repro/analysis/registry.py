@@ -76,6 +76,12 @@ ALLOWLIST: Tuple[Exemption, ...] = (
               "training throughput measurement (tokens/sec)"),
     Exemption("wallclock", "repro.analysis.__main__", "main",
               "the analyzer reports its own wall time; not sim semantics"),
+    Exemption("wallclock", "repro.obs.tracer", "Tracer.clock",
+              "wall spans of the real path: measured and reported, never "
+              "read by a scheduling decision"),
+    Exemption("wallclock", "repro.obs.tracer", "_WallSpan",
+              "wall spans of the real path: measured and reported, never "
+              "read by a scheduling decision"),
     # epoch-discipline: the one mutation site whose bump lives in callers
     Exemption("epoch", "repro.core.scheduler", "split_batch",
               "both call sites (Executor.start_next_batch, decode admit) "

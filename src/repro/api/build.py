@@ -400,10 +400,12 @@ def build_context(spec: DeploymentSpec,
     mode, engine = spec.serving.mode, spec.serving.engine
     policy = resolve_policy(spec)
     obs = spec.observability
+    real = spec.model.kind == "tiny"    # real JAX experts (RealEngine)
+    # the real path also keeps wall-clock spans (repro.obs.tracer)
     tracer = NULL_TRACER if obs.trace == "off" \
-        else Tracer(level=obs.trace, capacity=obs.buffer_events)
+        else Tracer(level=obs.trace, capacity=obs.buffer_events, wall=real)
 
-    if spec.model.kind == "tiny":
+    if real:
         m = spec.model
         system, coe = build_real_system(
             n_components=m.tiny_components, n_detection=m.tiny_detection,

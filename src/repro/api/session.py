@@ -189,6 +189,8 @@ class Session:
                "makespan_s": round(m.makespan, 3)}
         if m.decode:
             out["decode"] = m.decode
+        if m.wall:
+            out["spans"] = m.wall
         return out
 
     # ------------------------------------------------------------------ #

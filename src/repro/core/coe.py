@@ -44,6 +44,9 @@ class Request:
     root_arrival_time: Optional[float] = None  # first arrival of the chain:
     #                                    follow-ups inherit it so end-to-end
     #                                    latency spans the whole expert chain
+    wall_enqueued: Optional[float] = None  # wall clock at the last queue
+    #                                    entry; stamped only while the
+    #                                    tracer's wall spans are on
 
     def e2e_arrival(self) -> float:
         """Arrival time of the chain root (end-to-end latency anchor)."""

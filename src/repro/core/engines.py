@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.coe import CoEModel, Request
 from repro.memory import MemoryHierarchy, TierSpec
+from repro.obs import NULL_TRACER
 
 
 class SimEngine:
@@ -237,6 +238,11 @@ class RealEngine:
     transfer really completed. ``measured_load_time`` accumulates the wall
     time the workers actually spent moving timed (post-init) loads; it is
     surfaced in ``Metrics.memory['real_measured_load_s']``.
+
+    With the system's tracer's wall side on (``Tracer(wall=True)``, handed
+    over by ``bind_topology``), each call also keeps ``coserve.*`` wall
+    spans: ``execute`` and its phases, ``switch_wait``, ``transfer`` with
+    its ``fetch`` and ``device_put``, and ``evict``.
     """
 
     def __init__(self, coe: CoEModel, store: HostStore, apply_fns: Dict[str, Any]):
@@ -248,6 +254,9 @@ class RealEngine:
         self._topology = None
         self._hierarchy = None
         self._pending: Dict[str, dict] = {}
+        # the scheduler's predicted seconds of each queued transfer, kept
+        # beside the measured ones in the coserve.transfer span
+        self._predicted: Dict[str, float] = {}
         self._lock = threading.Lock()
         self.measured_load_time = 0.0
         # heterogeneous CPU co-execution (policy.host_exec): host/CPU
@@ -259,17 +268,22 @@ class RealEngine:
         # ``decode_attn`` overrides the cache geometry (heads/width/dtype).
         self.decode_caches: Dict[int, RingKVCache] = {}
         self.decode_attn: Dict[str, Any] = {}
+        self.tracer = NULL_TRACER
 
     # --- topology binding (one transfer thread per transfer channel) ---- #
-    def bind_topology(self, topology, hierarchy=None) -> None:
+    def bind_topology(self, topology, hierarchy=None, tracer=None) -> None:
         """Mirror the tier topology's channels: each PCIe channel, peer
         ingress link (or the SSD link on unified tiers) gets its own FIFO
         transfer thread, so the real backend serializes loads exactly where
         the simulator's contended channels would. ``hierarchy`` (when given)
         lets loads of experts already resident on a sibling pool ride that
-        pool's peer channel thread. Called by ``CoServeSystem``."""
+        pool's peer channel thread; ``tracer`` is the system's flight
+        recorder, whose wall spans time this engine's calls. Called by
+        ``CoServeSystem``."""
         self._topology = topology
         self._hierarchy = hierarchy
+        if tracer is not None:
+            self.tracer = tracer
 
     def _channel_name(self, ex, expert_id: str = "") -> str:
         if self._topology is None or ex is None:
@@ -314,13 +328,26 @@ class RealEngine:
     # ------------------------------------------------------------------ #
     def _transfer(self, expert_id: str, timed: bool = True):
         import jax
-        t0 = time.perf_counter()
-        host_params, _ = self.store.fetch(expert_id)
-        dev = jax.block_until_ready(jax.device_put(host_params))
-        with self._lock:
-            self.device_params[expert_id] = dev
-            if timed:
-                self.measured_load_time += time.perf_counter() - t0
+        span = self.tracer.span
+        predicted = self._predicted.pop(expert_id, None)
+        with span("coserve.transfer", expert=expert_id,
+                  bytes=self.coe.spec(expert_id).mem_bytes,
+                  predicted_s=predicted, timed=timed) as sp:
+            t0 = time.perf_counter()
+            with span("coserve.transfer.fetch") as fetch:
+                host_params, tier = self.store.fetch(expert_id)
+                fetch.set(tier=tier)
+            sp.set(tier=tier)
+            with span("coserve.transfer.device_put") as put:
+                dev = jax.block_until_ready(jax.device_put(host_params))
+                if self.tracer.wall:
+                    put.set(bytes=sum(leaf.nbytes
+                                      for leaf in jax.tree.leaves(dev)),
+                            timed=timed)
+            with self._lock:
+                self.device_params[expert_id] = dev
+                if timed:
+                    self.measured_load_time += time.perf_counter() - t0
 
     def load(self, ex, expert_id: str, now: float = 0.0) -> float:
         if self._host_exec_hit(ex, expert_id):
@@ -329,18 +356,22 @@ class RealEngine:
             with self._lock:
                 self.device_params[expert_id] = self.store.host[expert_id]
             return 0.0
+        predicted = self.load_latency(ex, expert_id)
+        if self.tracer.wall:
+            self._predicted[expert_id] = predicted
         worker = self._worker_for(self._channel_name(ex, expert_id))
         handle = worker.submit(lambda: self._transfer(expert_id))
         with self._lock:
             self._pending[expert_id] = handle
-        return self.load_latency(ex, expert_id)
+        return predicted
 
     def wait_load(self, ex, expert_id: str) -> None:
         """Block until the queued transfer landed (executor ``finish_load``)."""
         with self._lock:
             handle = self._pending.pop(expert_id, None)
         if handle is not None:
-            _TransferWorker.wait(handle)
+            with self.tracer.span("coserve.switch_wait", expert=expert_id):
+                _TransferWorker.wait(handle)
 
     def unload(self, ex, expert_id: str) -> None:
         """Evict: delete the expert's device buffers now, so the incoming
@@ -348,12 +379,13 @@ class RealEngine:
         working set, never pool plus one expert). Host-executed params are
         the host store's NumPy arrays and stay."""
         import jax
-        self.wait_load(ex, expert_id)    # never drop a half-landed transfer
-        with self._lock:
-            params = self.device_params.pop(expert_id, None)
-        for leaf in jax.tree.leaves(params):
-            if isinstance(leaf, jax.Array):
-                leaf.delete()
+        with self.tracer.span("coserve.evict", expert=expert_id):
+            self.wait_load(ex, expert_id)  # never drop a half-landed transfer
+            with self._lock:
+                params = self.device_params.pop(expert_id, None)
+            for leaf in jax.tree.leaves(params):
+                if isinstance(leaf, jax.Array):
+                    leaf.delete()
 
     def warm_place(self, pool, expert_id: str) -> None:
         """Initial placement (system-init phase): transfer without timing."""
@@ -392,19 +424,33 @@ class RealEngine:
         import jax
         spec = self.coe.spec(expert_id)
         payload = spec.payload or {}
-        t0 = time.perf_counter()
-        params = self.device_params[expert_id]
-        make_batch = payload["make_batch"]
-        interpret = payload.get("interpret", lambda o: list(o))
-        x = make_batch(batch)
-        # pad the batch dim to a power-of-two bucket: one XLA compile per
-        # bucket instead of one per group size (production bucketing)
-        n = x.shape[0]
-        bucket = 1 << (n - 1).bit_length()
-        if bucket != n:
-            pad = np.zeros((bucket - n,) + x.shape[1:], x.dtype)
-            x = np.concatenate([x, pad], axis=0)
-        out = self.apply_fns[spec.arch](params, x)
-        out = jax.block_until_ready(out)
-        lat = time.perf_counter() - t0
-        return interpret(np.asarray(out)[:n]), lat
+        tracer = self.tracer
+        span = tracer.span
+        with span("coserve.execute", expert=expert_id,
+                  rows=len(batch)) as step:
+            t0 = time.perf_counter()
+            params = self.device_params[expert_id]
+            make_batch = payload["make_batch"]
+            interpret = payload.get("interpret", lambda o: list(o))
+            with span("coserve.execute.inputs"):
+                x = make_batch(batch)
+                # pad the batch dim to a power-of-two bucket: one XLA
+                # compile per bucket instead of one per group size
+                # (production bucketing)
+                n = x.shape[0]
+                bucket = 1 << (n - 1).bit_length()
+                if bucket != n:
+                    pad = np.zeros((bucket - n,) + x.shape[1:], x.dtype)
+                    x = np.concatenate([x, pad], axis=0)
+            if tracer.wall:
+                step.set(bucket=bucket, requests=[r.id for r in batch])
+            with span("coserve.execute.dispatch"):
+                out = self.apply_fns[spec.arch](params, x)
+            with span("coserve.execute.device_wait"):
+                out = jax.block_until_ready(out)
+            lat = time.perf_counter() - t0
+            with span("coserve.execute.outputs") as outputs:
+                host = np.asarray(out)
+                outputs.set(bytes=host.nbytes)
+                result = interpret(host[:n])
+        return result, lat

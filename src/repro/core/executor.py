@@ -327,17 +327,25 @@ class Executor:
             self.queue.pop(0)
         else:
             bump_queue(self.queue)   # head group shrank in place
+        tracer = self.tracer
+        if tracer.wall:
+            # wall-clock queue wait of each stage: assign's stamp -> now
+            t = tracer.clock()
+            for r in batch:
+                if r.wall_enqueued is not None:
+                    tracer.record("coserve.queue", r.wall_enqueued, t,
+                                  expert=eid, request=r.id,
+                                  parent_request=r.parent_id)
         outputs, lat = self.engine.execute(self, eid, batch)
         self.pool.pin(eid)
         self.pool.touch(eid)
         self.current = (eid, batch, outputs)
         self.busy_until = now + lat
         self.stats.busy_time += lat
-        if self.tracer.full:
+        if tracer.full:
             on = "host" if self.device in ("host", "cpu") else "device"
-            self.tracer.emit(now, "exec", self.id, eid, dur=lat,
-                             requests=[r.id for r in batch], n=len(batch),
-                             on=on)
+            tracer.emit(now, "exec", self.id, eid, dur=lat,
+                        requests=[r.id for r in batch], n=len(batch), on=on)
         if self.hierarchy is not None:
             # dependency-aware cross-tier prefetch: while this expert runs,
             # promote its likely downstream experts disk -> host

@@ -114,6 +114,10 @@ class Metrics:
     #                                 # token-level decode snapshot (tokens,
     #                                 # TTFT/token percentiles, KV traffic);
     #                                 # empty when decode is off
+    wall: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #                                 # real path, wall spans on: per span
+    #                                 # name (``wall_summary``); empty
+    #                                 # otherwise
 
 
 @dataclasses.dataclass
@@ -169,7 +173,7 @@ class CoServeSystem:
             self.engine.host_exec_enabled = True
         bind = getattr(self.engine, "bind_topology", None)
         if bind is not None:     # real backend: one transfer thread per link
-            bind(self.hierarchy.topology, self.hierarchy)
+            bind(self.hierarchy.topology, self.hierarchy, tracer=self.tracer)
         self.manager = ExpertManager(coe, policy=policy.evict)
         self.executors: List[Executor] = []
         for i, spec in enumerate(executor_specs):
@@ -245,21 +249,27 @@ class CoServeSystem:
         return sum(e.queued_requests() for e in self.live_executors())
 
     def assign(self, req: Request, now: float) -> Executor:
-        t0 = time.perf_counter()
-        ex = self.scheduler.assign(req, now)
-        self.sched_time += time.perf_counter() - t0
-        self.expert_load[req.expert_id] = \
-            self.expert_load.get(req.expert_id, 0) + 1
-        if self.tracer.full:
-            # queue-arrival record: timeline reconstruction joins this with
-            # exec batch membership to recover per-stage queue waits
-            self.tracer.emit(now, "assign", "scheduler", req.expert_id,
-                             request=req.id, executor=ex.id,
-                             tenant=req.tenant, parent=req.parent_id)
-        # queue-arrival prefetch trigger: the request's expert just joined a
-        # queue, so its likely downstream experts can start promoting now
-        # (inert unless policy.prefetch_trigger == "queue")
-        self.hierarchy.on_enqueue(req.expert_id, now)
+        tracer = self.tracer
+        with tracer.span("coserve.schedule", expert=req.expert_id,
+                         request=req.id):
+            t0 = time.perf_counter()
+            ex = self.scheduler.assign(req, now)
+            self.sched_time += time.perf_counter() - t0
+            self.expert_load[req.expert_id] = \
+                self.expert_load.get(req.expert_id, 0) + 1
+            if tracer.full:
+                # queue-arrival record: timeline reconstruction joins this
+                # with exec batch membership to recover per-stage queue waits
+                tracer.emit(now, "assign", "scheduler", req.expert_id,
+                            request=req.id, executor=ex.id,
+                            tenant=req.tenant, parent=req.parent_id)
+            # queue-arrival prefetch trigger: the request's expert just
+            # joined a queue, so its likely downstream experts can start
+            # promoting now (inert unless policy.prefetch_trigger == "queue")
+            self.hierarchy.on_enqueue(req.expert_id, now)
+        if tracer.wall:
+            # closed by Executor.start_next_batch as a coserve.queue record
+            req.wall_enqueued = tracer.clock()
         return ex
 
     def route_followup(self, req: Request, expert_id: str, output) -> Optional[Request]:
@@ -445,4 +455,29 @@ class CoServeSystem:
             m.memory["real_measured_load_s"] = round(measured, 4)
         if self.decode is not None:
             m.decode = self.decode.metrics_snapshot()
+        if self.tracer.wall:
+            m.wall = wall_summary(self.tracer.wall_records)
         return m
+
+
+def wall_summary(records) -> Dict[str, Dict[str, float]]:
+    """Per wall span name: its count and summed seconds, the summed
+    ``bytes`` and ``predicted_s`` of the spans that carry them (a switch's
+    measured seconds beside the ones the scheduler planned with), and for
+    the queue wait (``coserve.queue``) its nearest-rank p50 and p90."""
+    out: Dict[str, Dict[str, float]] = {}
+    queue: List[float] = []
+    for r in list(records):
+        d = out.setdefault(r.name, {"count": 0, "seconds": 0.0})
+        d["count"] += 1
+        d["seconds"] += r.t1 - r.t0
+        for key in ("bytes", "predicted_s"):
+            if r.attrs.get(key) is not None:
+                d[key] = d.get(key, 0) + r.attrs[key]
+        if r.name == "coserve.queue":
+            queue.append(r.t1 - r.t0)
+    if queue:
+        queue.sort()
+        out["coserve.queue"]["p50_s"] = nearest_rank(queue, 0.50)
+        out["coserve.queue"]["p90_s"] = nearest_rank(queue, 0.90)
+    return dict(sorted(out.items()))

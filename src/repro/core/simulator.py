@@ -140,25 +140,9 @@ class Simulation:
                 ex = payload
                 if not ex.alive or ex.current is None:
                     continue
-                eid, batch, outputs = ex.finish_batch(t)
-                for i, req in enumerate(batch):
-                    out = outputs[i] if outputs else None
-                    if self.on_stage is not None:
-                        self.on_stage(self, req, eid, t)
-                    follow = sys.route_followup(req, eid, out)
-                    if follow is None:
-                        if self.decode is not None:
-                            # terminal stage = prefill: the request joins the
-                            # executor's continuous decode batch instead of
-                            # completing; it finishes at its last token
-                            self.decode.admit(ex, req, t)
-                        else:
-                            self.completed.append(req)
-                            if self.on_complete is not None:
-                                self.on_complete(self, req, t)
-                    else:
-                        follow.arrival_time = t
-                        self.push(t, ARRIVAL, follow)
+                with sys.tracer.span("coserve.route", expert=ex.current[0],
+                                     batch=len(ex.current[1])):
+                    self._route(ex, t)
                 self.kick(ex, t)
                 # a finished batch unpins its expert: pool-sharing peers whose
                 # pending load was blocked on that pin can now proceed
@@ -196,6 +180,29 @@ class Simulation:
         m.events_processed = n_events
         m.wall_s = time.perf_counter() - t0
         return m
+
+    def _route(self, ex: Executor, t: float):
+        """A finished batch: route each member to its follow-up stage, or
+        complete it (the completion hooks run here)."""
+        eid, batch, outputs = ex.finish_batch(t)
+        for i, req in enumerate(batch):
+            out = outputs[i] if outputs else None
+            if self.on_stage is not None:
+                self.on_stage(self, req, eid, t)
+            follow = self.system.route_followup(req, eid, out)
+            if follow is None:
+                if self.decode is not None:
+                    # terminal stage = prefill: the request joins the
+                    # executor's continuous decode batch instead of
+                    # completing; it finishes at its last token
+                    self.decode.admit(ex, req, t)
+                else:
+                    self.completed.append(req)
+                    if self.on_complete is not None:
+                        self.on_complete(self, req, t)
+            else:
+                follow.arrival_time = t
+                self.push(t, ARRIVAL, follow)
 
     # ------------------------------------------------------------------ #
     def kick(self, ex: Executor, now: float):

@@ -102,6 +102,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",  # the op's name in HLO and device traces
         interpret=interpret,
     )(pos_arr, qf, kf, vf)
     return out.reshape(b, h, d)

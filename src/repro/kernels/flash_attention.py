@@ -129,6 +129,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",  # the op's name in HLO and device traces
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s + s_pad, d)[:, :, :s]

@@ -108,6 +108,7 @@ def mamba_scan(x, dt, b_mat, c_mat, a, d_vec, *, block_d: int = 128,
                         pltpu.VMEM((block_s, block_d), jnp.float32)],  # y
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="mamba_scan",  # the op's name in HLO and device traces
         interpret=interpret,
     )(x, dt, b_mat, c_mat, a, d_vec.reshape(1, d))
     return y[:, :s], h_final

@@ -293,3 +293,238 @@ def test_latency_tracker_marks_low_confidence_tails():
     for i in range(2000):
         lt.add(0.01)
     assert lt.snapshot()["low_confidence"] == []
+
+
+# --------------------------------------------------------------------------- #
+# wall-clock spans (the real serving path)
+# --------------------------------------------------------------------------- #
+
+from repro.obs.tracer import NULL_SPAN
+
+EXECUTE_PHASES = ("coserve.execute.inputs", "coserve.execute.dispatch",
+                  "coserve.execute.device_wait", "coserve.execute.outputs")
+
+
+def _real_spec(trace: str = "full", requests: int = 40) -> DeploymentSpec:
+    return DeploymentSpec(
+        model=ModelSpec(kind="tiny", tiny_components=8, tiny_detection=2,
+                        tiny_pool_experts=3, tiny_d_hidden=64),
+        serving=ServingSection(mode="real"),
+        workload=WorkloadSection(requests=requests),
+        observability=ObservabilitySection(trace=trace))
+
+
+def _pinned_profiles(monkeypatch):
+    """Pin the offline profile (normally measured) so two builds schedule
+    alike."""
+    import repro.api.build as build
+
+    measure = build.microbenchmark_arch
+
+    def pinned(*a, **kw):
+        return dataclasses.replace(measure(*a, **kw), k=1e-3, b=2e-3,
+                                   max_batch=4, cpu_k=0.0, cpu_b=0.0)
+    monkeypatch.setattr(build, "microbenchmark_arch", pinned)
+
+
+def _fixed_latency(sess):
+    """Report each batch's latency as a function of its size, so the
+    sim-time stream depends on the code alone."""
+    engine = sess.system.engine
+    execute = engine.execute
+
+    def fixed(ex, expert_id, batch):
+        out, _ = execute(ex, expert_id, batch)
+        return out, 1e-3 * len(batch) + 2e-3
+    engine.execute = fixed
+
+
+@pytest.fixture(scope="module")
+def real_traced():
+    """One tiny real-engine run with wall spans on."""
+    sess, out = _run(_real_spec())
+    return sess, out, list(sess.system.tracer.wall_records)
+
+
+def test_wall_spans_off_costs_a_null_context():
+    assert NULL_TRACER.wall is False
+    assert NULL_TRACER.span("coserve.execute", expert="e") is NULL_SPAN
+    assert Tracer("full").span("x") is NULL_SPAN
+    # only an enabled tracer records wall spans
+    assert Tracer("off", wall=True).wall is False
+    with NULL_SPAN as sp:
+        sp.set(bytes=1)
+    assert len(NULL_TRACER.wall_records) == 0
+
+
+def test_trace_off_real_run_keeps_null_tracer_and_no_wall_record():
+    sess, out = _run(_real_spec(trace="off", requests=12))
+    assert sess.system.tracer is NULL_TRACER
+    assert sess.system.engine.tracer is NULL_TRACER
+    assert len(NULL_TRACER.wall_records) == 0
+    assert sess.metrics().wall == {} and "spans" not in out
+
+
+def test_sim_runs_never_turn_wall_spans_on():
+    sess, _ = _run(_spec(trace="full", requests=40))
+    assert sess.system.tracer.wall is False
+    assert len(sess.system.tracer.wall_records) == 0
+    assert sess.metrics().wall == {}
+
+
+def test_wall_ring_is_bounded_and_counts_drops():
+    tr = Tracer("summary", capacity=2, wall=True)
+    for i in range(3):
+        with tr.span("coserve.test", i=i):
+            pass
+    assert [r.attrs["i"] for r in tr.wall_records] == [1, 2]
+    assert tr.wall_dropped == 1
+
+
+def test_wall_ring_keeps_every_record_of_concurrent_threads():
+    """The transfer threads record beside the serving thread: no record or
+    drop may be lost, and ids stay unique."""
+    import threading
+    tr = Tracer("summary", capacity=500, wall=True)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(200):
+                with tr.span("coserve.outer"):
+                    with tr.span("coserve.outer.inner"):
+                        pass
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(switch)
+    assert len(tr.wall_records) + tr.wall_dropped == 8 * 200 * 2
+    assert len({r.id for r in tr.wall_records}) == len(tr.wall_records)
+    # each inner span's parent is the outer span of its own thread
+    by_id = {r.id: r for r in tr.wall_records}
+    inner = [r for r in tr.wall_records if r.name == "coserve.outer.inner"]
+    assert inner and all(by_id[r.parent].thread == r.thread
+                         for r in inner if r.parent in by_id)
+    assert all(r.parent is None for r in tr.wall_records
+               if r.name == "coserve.outer")
+
+
+def test_span_records_when_its_body_raises_and_nests_by_thread():
+    tr = Tracer("summary", wall=True)
+    with pytest.raises(KeyError):
+        with tr.span("coserve.outer") as outer:
+            with tr.span("coserve.inner") as inner:
+                inner.set(rows=3)
+                raise KeyError("x")
+    by_name = {r.name: r for r in tr.wall_records}
+    assert by_name["coserve.inner"].parent == by_name["coserve.outer"].id
+    assert by_name["coserve.outer"].parent is None
+    assert by_name["coserve.inner"].attrs == {"rows": 3}
+    t0 = tr.clock()
+    tr.record("coserve.queue", t0 - 0.5, t0, request=7)
+    rec = tr.wall_records[-1]
+    assert rec.parent is None and rec.dur == pytest.approx(0.5)
+    assert outer.id != inner.id != rec.id
+
+
+def test_real_run_children_nest_inside_their_parent(real_traced):
+    _, _, records = real_traced
+    by_id = {r.id: r for r in records}
+    children = [r for r in records if r.parent is not None]
+    assert {r.name for r in children} >= set(EXECUTE_PHASES) | {
+        "coserve.transfer.fetch", "coserve.transfer.device_put"}
+    for r in children:
+        p = by_id[r.parent]
+        assert p.thread == r.thread
+        assert p.t0 <= r.t0 <= r.t1 <= p.t1, (p, r)
+        assert r.name.startswith(p.name + ".") or p.name in (
+            "coserve.evict", "coserve.route", "coserve.schedule")
+    # every model step has its four phases, summing to at most the step
+    steps = [r for r in records if r.name == "coserve.execute"]
+    assert steps
+    for step in steps:
+        kids = [r for r in children if r.parent == step.id]
+        assert sorted(k.name for k in kids) == sorted(EXECUTE_PHASES)
+        assert sum(k.dur for k in kids) <= step.dur
+        assert step.attrs["rows"] <= step.attrs["bucket"]
+        assert len(step.attrs["requests"]) == step.attrs["rows"]
+    outputs = [r for r in records if r.name == "coserve.execute.outputs"]
+    assert all(r.attrs["bytes"] > 0 for r in outputs)
+
+
+def test_real_run_one_queue_record_per_executed_stage(real_traced):
+    sess, _, records = real_traced
+    executed = [rid for e in sess.system.tracer.events if e.kind == "exec"
+                for rid in e.attrs["requests"]]
+    queued = [r.attrs["request"] for r in records
+              if r.name == "coserve.queue"]
+    assert sorted(queued) == sorted(executed)
+    stepped = [rid for r in records if r.name == "coserve.execute"
+               for rid in r.attrs["requests"]]
+    assert sorted(stepped) == sorted(executed)
+    assert all(r.t1 >= r.t0 for r in records if r.name == "coserve.queue")
+    # follow-up stages name the request they continue
+    assert any(r.attrs["parent_request"] is not None for r in records
+               if r.name == "coserve.queue")
+
+
+def test_real_run_transfer_thread_spans_carry_bytes_and_tier(real_traced):
+    _, _, records = real_traced
+    transfers = [r for r in records if r.name == "coserve.transfer"]
+    timed = [r for r in transfers if r.attrs["timed"]]
+    assert timed and all(r.attrs["predicted_s"] > 0 for r in timed)
+    for r in transfers:
+        # switches ride a transfer thread; warm placement runs in line
+        assert (r.thread != "MainThread") == r.attrs["timed"]
+        assert r.attrs["bytes"] > 0 and r.attrs["tier"] in ("host", "disk")
+    fetches = [r for r in records if r.name == "coserve.transfer.fetch"]
+    puts = [r for r in records if r.name == "coserve.transfer.device_put"]
+    assert len(fetches) == len(puts) == len(transfers)
+    assert all(r.attrs["tier"] in ("host", "disk") for r in fetches)
+    assert all(r.attrs["bytes"] > 0 for r in puts)
+    # a switch the executor waited for, and an eviction that made room
+    names = {r.name for r in records}
+    assert {"coserve.switch_wait", "coserve.evict", "coserve.schedule",
+            "coserve.route"} <= names
+
+
+def test_real_run_reports_wall_summary(real_traced):
+    sess, out, records = real_traced
+    wall = sess.metrics().wall
+    assert out["spans"] == wall
+    assert wall["coserve.execute"]["count"] == sum(
+        r.name == "coserve.execute" for r in records)
+    q = wall["coserve.queue"]
+    assert 0 <= q["p50_s"] <= q["p90_s"]
+    # a switch's measured seconds sit beside the predicted ones
+    transfers = [r for r in records if r.name == "coserve.transfer"]
+    assert wall["coserve.transfer"]["predicted_s"] == pytest.approx(sum(
+        r.attrs["predicted_s"] for r in transfers if r.attrs["timed"]))
+    assert wall["coserve.transfer.device_put"]["bytes"] > 0
+    assert wall["coserve.execute.outputs"]["bytes"] > 0
+    assert q["seconds"] == pytest.approx(sum(
+        r.dur for r in records if r.name == "coserve.queue"))
+
+
+def test_wall_spans_leave_the_sim_time_stream_identical(monkeypatch):
+    _pinned_profiles(monkeypatch)
+    streams, walls = [], []
+    for wall in (False, True):
+        sess = Session(_real_spec(requests=30))
+        if wall:
+            assert sess.system.tracer.wall
+        else:
+            sess.system.tracer.wall = False     # the same build, spans off
+        _fixed_latency(sess)
+        before = len(sess.system.tracer.wall_records)   # warm placement
+        sess.run()
+        streams.append(sess.system.tracer.to_dicts())
+        walls.append(len(sess.system.tracer.wall_records) - before)
+    assert streams[0] == streams[1]
+    assert {"load", "exec", "assign", "sched"} <= {e["kind"]
+                                                  for e in streams[0]}
+    assert walls[0] == 0 < walls[1]
