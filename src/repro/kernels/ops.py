@@ -24,8 +24,9 @@ def interpret_mode() -> bool:
 
 
 def flash_attention_op(q, k, v, *, causal=True, window=0, impl="pallas",
-                       block_q=128, block_k=128):
-    """q: [B,H,S,D]; k,v: [B,Hkv,T,D]."""
+                       block_q=None, block_k=None):
+    """q: [B,H,S,D]; k,v: [B,Hkv,T,D]. Blocks default to the kernel's plan
+    (``flash_attention_plan``)."""
     if impl == "xla":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention(q, k, v, causal=causal, window=window,

@@ -7,7 +7,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                          flash_attention_plan)
 from repro.kernels.mamba_scan import mamba_scan
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
@@ -33,6 +34,10 @@ def rand(key, shape, dtype):
     (1, 8, 2, 256, 256, 64),     # GQA group 4, two q blocks
     (1, 4, 1, 128, 256, 64),     # MQA, cached prefix (t > s)
     (2, 4, 4, 128, 128, 128),    # head_dim 128 (MXU width)
+    (1, 12, 1, 256, 256, 128),   # KV group of 12 (starcoder2), two q blocks
+    (1, 6, 2, 256, 256, 128),    # KV group of 3 (phi4)
+    (1, 12, 1, 300, 300, 64),    # s and t not multiples of the blocks
+    (1, 6, 2, 128, 384, 64),     # group of 3 over a cached prefix (t > s)
 ])
 def test_flash_attention_matches_ref(b, h, hkv, s, t, d, dtype):
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -45,7 +50,7 @@ def test_flash_attention_matches_ref(b, h, hkv, s, t, d, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("window", [32, 64, 128])
+@pytest.mark.parametrize("window", [32, 64, 128, 200])
 def test_flash_attention_sliding_window(window):
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
     b, h, s, d = 1, 4, 256, 64
@@ -72,6 +77,30 @@ def test_flash_attention_block_shapes(block_q, block_k):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [0, 100])
+def test_flash_attention_kv_chunks(monkeypatch, window):
+    """Keys that do not fit VMEM at once stream in chunks along the grid's
+    sequential axis; a chunk outside the window is neither computed nor
+    fetched anew."""
+    import repro.kernels.flash_attention as fa
+    monkeypatch.setattr(fa, "KV_VMEM_BYTES", 128 * 2 * 2 * 64 * 4)
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    b, h, hkv, s, t, d = 1, 4, 2, 144, 400, 64
+    q = rand(keys[0], (b, h, s, d), jnp.float32)
+    k = rand(keys[1], (b, hkv, t, d), jnp.float32)
+    v = rand(keys[2], (b, hkv, t, d), jnp.float32)
+    plan = fa.flash_attention_plan(s, t, h // hkv, d, jnp.float32,
+                                   causal=True, window=window, block_k=128)
+    assert plan.n_chunks == 4
+    flash_attention.clear_cache()
+    out = flash_attention(q, k, v, causal=True, window=window, block_k=128,
+                          interpret=True)
+    flash_attention.clear_cache()
+    want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_flash_attention_noncausal():
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     q = rand(keys[0], (1, 2, 128, 64), jnp.float32)
@@ -81,6 +110,60 @@ def test_flash_attention_noncausal():
     want = ref.flash_attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def _allowed(plan, causal, window):
+    """[s_pad, t_pad] bool: the (query, key) pairs the call's mask lets
+    through, padded query rows continuing the positions, padded keys none."""
+    q = np.arange(plan.s_pad)[:, None] + plan.seq_k - plan.seq_q
+    k = np.arange(plan.t_pad)[None, :]
+    ok = np.broadcast_to(k < plan.seq_k, (plan.s_pad, plan.t_pad))
+    if causal:
+        ok = ok & (q >= k)
+    if window:
+        ok = ok & (q - k < window)
+    return ok
+
+
+@pytest.mark.parametrize("s,t,g,dtype,causal,window", [
+    (1024, 1024, 12, jnp.bfloat16, True, 4096),   # starcoder2, served prompt
+    (1024, 1024, 3, jnp.bfloat16, True, 0),       # phi4-mini
+    (64, 64, 12, jnp.bfloat16, True, 4096),       # chip smoke's prompt
+    (300, 700, 3, jnp.float32, True, 200),        # prefix, window in a tile
+    (256, 256, 1, jnp.float32, False, 0),         # non-causal
+    (100, 40000, 1, jnp.bfloat16, True, 0),       # keys span several chunks
+])
+def test_flash_attention_plan_tiles(s, t, g, dtype, causal, window):
+    """The plan schedules exactly the tiles the mask needs, masks only the
+    ones it cuts, and keeps the blocks whole."""
+    plan = flash_attention_plan(s, t, g, 128, dtype, causal=causal,
+                                window=window)
+    bq, bk = plan.block_q, plan.block_k
+    assert bq % 16 == 0 and bk % 16 == 0 and plan.kv_chunk % bk == 0
+    ok = _allowed(plan, causal, window)
+    tiles = list(plan.tiles())
+    assert len(set(tiles)) == len(tiles)
+    covered = np.zeros_like(ok)
+    for qi, kj, masked in tiles:
+        tile = ok[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+        assert tile.any()                     # no dead tile is scheduled
+        assert masked == (not tile.all())     # the mask only where it cuts
+        covered[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk] = True
+    assert not (ok & ~covered)[:s].any()      # every allowed pair is scored
+
+
+def test_flash_attention_plan_served_shape():
+    """At the served shape (starcoder2: q [4, 24, 1024, 128], k/v
+    [4, 2, 1024, 128], bf16, causal) no tile above the diagonal is
+    scheduled, and the grid has at least 8x fewer steps than the 6,144 of
+    one program per (head, q block, kv block) of 128."""
+    plan = flash_attention_plan(1024, 1024, 12, 128, jnp.bfloat16,
+                                causal=True, window=4096)
+    grid = plan.grid(4, 2)
+    assert np.prod(grid) * 8 <= 4 * 24 * 8 * 8
+    assert plan.group * plan.block_q >= 512      # MXU rows per program
+    for qi, kj, _ in plan.tiles():
+        assert kj * plan.block_k <= (qi + 1) * plan.block_q - 1
 
 
 # --------------------------------------------------------------------------- #

@@ -78,12 +78,18 @@ def test_decode_attention_compiles_for_v5e(one_chip, b, h, hkv, d, w, dtype):
             q, kv, kv, pos)
 
 
-@pytest.mark.parametrize("s", [16, 2048])
-def test_flash_attention_compiles_for_v5e(one_chip, s):
-    q = _shape(one_chip, (1, 24, s, 128), jnp.bfloat16)     # starcoder2_3b
-    kv = _shape(one_chip, (1, 2, s, 128), jnp.bfloat16)
-    _native(lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                            interpret=False), q, kv, kv)
+@pytest.mark.parametrize("b,hkv,s", [
+    (1, 2, 16), (1, 2, 2048),               # starcoder2_3b widths
+    (4, 2, 1024),                           # starcoder2_3b, the served batch
+    (4, 8, 1024),                           # phi4_mini_3_8b: 8 KV heads
+], ids=["16", "2048", "sc2-served", "phi4-served"])
+def test_flash_attention_compiles_for_v5e(one_chip, b, hkv, s):
+    q = _shape(one_chip, (b, 24, s, 128), jnp.bfloat16)
+    kv = _shape(one_chip, (b, hkv, s, 128), jnp.bfloat16)
+    compiled = _native(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=4096 if hkv == 2 else 0,
+        interpret=False), q, kv, kv)
+    assert "flash_attention" in compiled.as_text()
 
 
 def test_mamba_scan_compiles_for_v5e(one_chip):
