@@ -18,6 +18,7 @@ ARCH_IDS = [
     "mixtral_8x22b",
     "falcon_mamba_7b",
     "qwen2_vl_2b",
+    "jamba2_3b",
 ]
 
 _ALIASES = {a.replace("_", "-"): a for a in ARCH_IDS}

@@ -1,6 +1,8 @@
 """Jamba v0.1 52B [arXiv:2403.19887; hf]: hybrid Mamba+attention (1:7
 interleave, attention at period-8 offset 4) with MoE (16 experts, top-2)
-on every other layer."""
+on every other layer. As published, attention has no positional encoding
+(the Mamba layers carry order) and each Mamba mixer normalises dt, B and C
+with an RMSNorm after ``x_proj``."""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -20,4 +22,7 @@ CONFIG = ModelConfig(
     attn_period=8,
     attn_offset=4,
     ssm_state_dim=16,
+    ssm_inner_norms=True,
+    position_encoding="none",
+    norm_eps=1e-6,
 )

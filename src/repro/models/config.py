@@ -51,8 +51,11 @@ class ModelConfig:
     ssm_state_dim: int = 0
     ssm_conv_width: int = 4
     ssm_expand: int = 2
+    ssm_dt_rank: int = 0           # 0 -> ceil(d_model / 16); jamba states it
+    ssm_inner_norms: bool = False  # jamba: RMSNorm on dt, B and C after x_proj
 
     # --- attention details ---
+    position_encoding: str = "rope"  # rope | none (jamba: mamba layers carry order)
     rope_theta: float = 10000.0
     sliding_window: int = 0        # 0 = full attention
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl M-RoPE half-dim split
@@ -97,7 +100,7 @@ class ModelConfig:
 
     @property
     def dt_rank(self) -> int:
-        return max(1, math.ceil(self.d_model / 16))
+        return self.ssm_dt_rank or max(1, math.ceil(self.d_model / 16))
 
     @property
     def is_attention_free(self) -> bool:
@@ -162,9 +165,12 @@ class ModelConfig:
                 total += n * (qkv + self.num_heads * hd * d + d)
             else:  # mamba
                 di, st, rk = self.d_inner, self.ssm_state_dim, self.dt_rank
+                norms = rk + 2 * st if self.ssm_inner_norms else 0
+                # in/x/dt/out projections, conv weight and bias, dt bias,
+                # A, D, the pre-norm and the inner norms
                 total += n * (d * 2 * di + di * self.ssm_conv_width
-                              + di * (rk + 2 * st) + rk * di + di * st + di
-                              + di * d + d)
+                              + di * (rk + 2 * st) + rk * di + di * st
+                              + 3 * di + di * d + d + norms)
             if slot.ffn == "mlp":
                 mult = 3 if self.mlp_type == "swiglu" else 2
                 total += n * (mult * d * self.d_ff + d)

@@ -290,6 +290,8 @@ def attention_block(params, x, cfg, positions, *, cache=None, pos=None,
         (returns (out, (k, v)) so callers can build a cache);
       - decode: cache=(k_cache, v_cache), pos given -> ring decode;
       - cross-attention: cross_kv=(k, v) precomputed (whisper decoder).
+    q and k are rotated (RoPE) where ``positions`` is given and the
+    architecture has ``position_encoding == "rope"``.
     """
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
@@ -300,7 +302,7 @@ def attention_block(params, x, cfg, positions, *, cache=None, pos=None,
              ).reshape(b, s, cfg.num_kv_heads, hd)
         v = (x @ cast_param(params["wv"], compute_dtype, *ATTN_AXES["wv"])
              ).reshape(b, s, cfg.num_kv_heads, hd)
-        if positions is not None:
+        if positions is not None and cfg.position_encoding == "rope":
             q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     else:

@@ -51,7 +51,7 @@ def _slot_axes(cfg: ModelConfig, slot):
     if slot.mixer == "attn":
         a["attn"] = dict(L.ATTN_AXES)
     else:
-        a["mamba"] = dict(ssm_lib.MAMBA_AXES)
+        a["mamba"] = ssm_lib.mamba_axes(cfg)
     if slot.ffn is not None:
         a["norm2"] = dict(a["norm1"])
         if slot.ffn == "moe":
@@ -118,31 +118,36 @@ def param_axes(cfg: ModelConfig):
 def _apply_slot(slot_params, x, cfg: ModelConfig, slot, positions, cdtype,
                 cache=None, pos=None):
     """One layer: pre-norm mixer + residual, then pre-norm FFN + residual.
-    Returns (x, new_cache, aux)."""
-    h = L.apply_norm(x, slot_params["norm1"], cfg.norm_type, cfg.norm_eps)
-    new_cache = None
-    if slot.mixer == "attn":
-        kv = None if cache is None else (cache["k"], cache["v"])
-        out, new_kv = L.attention_block(
-            slot_params["attn"], h, cfg, positions, cache=kv, pos=pos,
-            compute_dtype=cdtype)
-        if cache is not None:
-            new_cache = {"k": new_kv[0], "v": new_kv[1]}
+    Returns (x, new_cache, aux). Each part runs under a ``jax.named_scope``
+    named for its kind (``attention``, ``mamba``, ``mlp``, ``moe``), which
+    the ops' metadata in HLO and in device traces carries."""
+    with jax.named_scope("attention" if slot.mixer == "attn" else "mamba"):
+        h = L.apply_norm(x, slot_params["norm1"], cfg.norm_type, cfg.norm_eps)
+        if slot.mixer == "attn":
+            kv = None if cache is None else (cache["k"], cache["v"])
+            out, new_kv = L.attention_block(
+                slot_params["attn"], h, cfg, positions, cache=kv, pos=pos,
+                compute_dtype=cdtype)
+            if cache is not None:
+                new_cache = {"k": new_kv[0], "v": new_kv[1]}
+            else:
+                new_cache = new_kv  # (k, v) of this segment (prefill harvests it)
         else:
-            new_cache = new_kv  # (k, v) of this segment (prefill harvests it)
-    else:
-        state = cache if (cache is not None and "ssm" in cache) else None
-        out, new_state = ssm_lib.mamba_forward(
-            slot_params["mamba"], h, cfg, cdtype, state=state)
-        new_cache = new_state
+            state = cache if (cache is not None and "ssm" in cache) else None
+            out, new_cache = ssm_lib.mamba_forward(
+                slot_params["mamba"], h, cfg, cdtype, state=state)
     x = x + out
     aux = jnp.zeros((), jnp.float32)
     if slot.ffn is not None:
-        h2 = L.apply_norm(x, slot_params["norm2"], cfg.norm_type, cfg.norm_eps)
-        if slot.ffn == "moe":
-            out2, aux, _ = moe_lib.moe_block(slot_params["moe"], h2, cfg, cdtype)
-        else:
-            out2 = L.mlp_block(slot_params["mlp"], h2, cfg.mlp_type, cdtype)
+        with jax.named_scope(slot.ffn):
+            h2 = L.apply_norm(x, slot_params["norm2"], cfg.norm_type,
+                              cfg.norm_eps)
+            if slot.ffn == "moe":
+                out2, aux, _ = moe_lib.moe_block(slot_params["moe"], h2, cfg,
+                                                 cdtype)
+            else:
+                out2 = L.mlp_block(slot_params["mlp"], h2, cfg.mlp_type,
+                                   cdtype)
         x = x + out2
     return x, new_cache, aux
 

@@ -96,10 +96,25 @@ def test_jamba_block_pattern():
     assert sum(1 for s in pat if s.ffn == "moe") == 4         # every other
 
 
+def test_jamba2_layout_and_params():
+    """Jamba2-3B: one period of 14 layers scanned twice, attention at slot
+    7 only, a dense MLP on every layer; the parameter count (inner norms
+    included) is the published model's and the built tree's."""
+    import jax
+    from repro.models import transformer
+    cfg = get_config("jamba2_3b")
+    pat = cfg.block_pattern()
+    assert [i for i, s in enumerate(pat) if s.mixer == "attn"] == [7]
+    assert {s.ffn for s in pat} == {"mlp"} and cfg.num_periods() == 2
+    leaves = jax.tree.leaves(transformer.abstract_params(cfg))
+    assert cfg.param_count() == sum(a.size for a in leaves) == 3_029_337_472
+
+
 def test_applicable_shapes_long_context_gating():
     longs = {a for a in ARCH_IDS
              if "long_500k" in applicable_shapes(get_config(a))}
-    assert longs == {"jamba_v0_1_52b", "mixtral_8x22b", "falcon_mamba_7b"}
+    assert longs == {"jamba_v0_1_52b", "mixtral_8x22b", "falcon_mamba_7b",
+                     "jamba2_3b"}
 
 
 def test_all_archs_have_all_base_shapes():

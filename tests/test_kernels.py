@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.kernels.flash_attention as fa_mod
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import (flash_attention,
@@ -38,6 +39,7 @@ def rand(key, shape, dtype):
     (1, 6, 2, 256, 256, 128),    # KV group of 3 (phi4)
     (1, 12, 1, 300, 300, 64),    # s and t not multiples of the blocks
     (1, 6, 2, 128, 384, 64),     # group of 3 over a cached prefix (t > s)
+    (1, 20, 1, 160, 160, 64),    # KV group of 20 (jamba2), not a power of 2
 ])
 def test_flash_attention_matches_ref(b, h, hkv, s, t, d, dtype):
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -132,6 +134,7 @@ def _allowed(plan, causal, window):
     (300, 700, 3, jnp.float32, True, 200),        # prefix, window in a tile
     (256, 256, 1, jnp.float32, False, 0),         # non-causal
     (100, 40000, 1, jnp.bfloat16, True, 0),       # keys span several chunks
+    (1024, 1024, 20, jnp.bfloat16, True, 0),      # jamba2: 20 heads, 1 KV
 ])
 def test_flash_attention_plan_tiles(s, t, g, dtype, causal, window):
     """The plan schedules exactly the tiles the mask needs, masks only the
@@ -164,6 +167,19 @@ def test_flash_attention_plan_served_shape():
     assert plan.group * plan.block_q >= 512      # MXU rows per program
     for qi, kj, _ in plan.tiles():
         assert kj * plan.block_k <= (qi + 1) * plan.block_q - 1
+
+
+def test_flash_attention_plan_group_of_20():
+    """Jamba2's served shape (q [B, 20, 1024, 128] over one KV head, bf16):
+    the plan needs no model name. The folded query tile stays within the
+    score budget with whole bf16 tiles, and the grid is one program row per
+    (batch, KV head) and query block."""
+    plan = flash_attention_plan(1024, 1024, 20, 128, jnp.bfloat16,
+                                causal=True)
+    assert plan.group == 20 and plan.block_q % 16 == 0
+    assert plan.group * plan.block_q * plan.block_k <= fa_mod.SCORE_TILE
+    assert plan.grid(4, 1) == (4, 1024 // plan.block_q, 1)
+    assert plan.s_pad == plan.t_pad == 1024
 
 
 # --------------------------------------------------------------------------- #

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,47 +79,52 @@ def test_decode_attention_compiles_for_v5e(one_chip, b, h, hkv, d, w, dtype):
             q, kv, kv, pos)
 
 
-@pytest.mark.parametrize("b,hkv,s", [
-    (1, 2, 16), (1, 2, 2048),               # starcoder2_3b widths
-    (4, 2, 1024),                           # starcoder2_3b, the served batch
-    (4, 8, 1024),                           # phi4_mini_3_8b: 8 KV heads
-], ids=["16", "2048", "sc2-served", "phi4-served"])
-def test_flash_attention_compiles_for_v5e(one_chip, b, hkv, s):
-    q = _shape(one_chip, (b, 24, s, 128), jnp.bfloat16)
+@pytest.mark.parametrize("b,h,hkv,s,window", [
+    (1, 24, 2, 16, 4096), (1, 24, 2, 2048, 4096),   # starcoder2_3b widths
+    (4, 24, 2, 1024, 4096),                 # starcoder2_3b, the served batch
+    (4, 24, 8, 1024, 0),                    # phi4_mini_3_8b: 8 KV heads
+    (8, 20, 1, 1024, 0),                    # jamba2_3b: 20 heads over 1 KV
+], ids=["16", "2048", "sc2-served", "phi4-served", "jamba2-served"])
+def test_flash_attention_compiles_for_v5e(one_chip, b, h, hkv, s, window):
+    q = _shape(one_chip, (b, h, s, 128), jnp.bfloat16)
     kv = _shape(one_chip, (b, hkv, s, 128), jnp.bfloat16)
     compiled = _native(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, window=4096 if hkv == 2 else 0,
-        interpret=False), q, kv, kv)
+        q, k, v, causal=True, window=window, interpret=False), q, kv, kv)
     assert "flash_attention" in compiled.as_text()
 
 
-def test_mamba_scan_compiles_for_v5e(one_chip):
-    b, s, d, n = 1, 256, 8192, 16                 # falcon_mamba_7b d_inner
+@pytest.mark.parametrize("b,s,d,block_s,io_dtype", [
+    (1, 256, 8192, 128, jnp.bfloat16),      # falcon_mamba_7b d_inner
+    # jamba2_3b as served: d_inner 5120, the model's ssm_chunk of 256, x in
+    # bf16 and dt/B/C in f32 (the mixer's dtypes)
+    (8, 1024, 5120, 256, jnp.float32),
+], ids=["falcon-mamba", "jamba2-served"])
+def test_mamba_scan_compiles_for_v5e(one_chip, b, s, d, block_s, io_dtype):
+    n = 16
     x = _shape(one_chip, (b, s, d), jnp.bfloat16)
-    bc = _shape(one_chip, (b, s, n), jnp.bfloat16)
+    dt = _shape(one_chip, (b, s, d), io_dtype)
+    bc = _shape(one_chip, (b, s, n), io_dtype)
     a = _shape(one_chip, (d, n), jnp.float32)
     d_vec = _shape(one_chip, (d,), jnp.float32)
-    _native(lambda x, dt, bm, cm, a, dv: mamba_scan(x, dt, bm, cm, a, dv,
-                                                    interpret=False),
-            x, x, bc, bc, a, d_vec)
+    compiled = _native(
+        lambda x, dt, bm, cm, a, dv: mamba_scan(
+            x, dt, bm, cm, a, dv, block_s=block_s, interpret=False),
+        x, dt, bc, bc, a, d_vec)
+    assert "mamba_scan" in compiled.as_text()
 
 
-def test_starcoder2_forward_two_experts_fit_v5e(one_chip, monkeypatch):
-    """The served full-width forward (Pallas attention, bf16 weights)
-    compiles with native kernels, and two experts' weights plus its working
-    set fit one chip's HBM (the pool the chip smoke serves from)."""
-    import repro.kernels.ops as ops
-    from repro.configs import get_config
+def _served(cfg):
+    return dataclasses.replace(cfg, param_dtype="bfloat16",
+                               attn_impl="pallas", remat=False)
+
+
+def _forward_fits_two_experts(one_chip, cfg, batch, seq):
+    """Compile the served forward for one chip and return the compiled
+    text; two experts' weights plus its working set fit the chip's HBM."""
     from repro.models import transformer
-
-    # the CPU process would pick interpret mode; compile the chip's branch
-    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
-    cfg = dataclasses.replace(get_config("starcoder2_3b"),
-                              param_dtype="bfloat16", attn_impl="pallas",
-                              remat=False)
     params = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
                           transformer.abstract_params(cfg))
-    tokens = _shape(one_chip, (8, 64), jnp.int32)
+    tokens = _shape(one_chip, (batch, seq), jnp.int32)
     compiled = _native(
         lambda p, t: transformer.forward(p, t, cfg, mode="eval")[0][:, -1],
         params, tokens)
@@ -127,3 +133,46 @@ def test_starcoder2_forward_two_experts_fit_v5e(one_chip, monkeypatch):
     assert mem.argument_size_in_bytes >= expert
     assert 2 * expert + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes < V5E_HBM_BYTES
+    return compiled.as_text()
+
+
+def test_jamba2_forward_two_experts_fit_v5e(one_chip, monkeypatch):
+    """Jamba2-3B as the benchmark serves it (Pallas attention and scan, bf16
+    weights), at the largest profiled batch of 1024-token prompts: both
+    kernels lower natively under their own names, and two experts fit."""
+    import repro.kernels.ops as ops
+    from repro.configs import get_config
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    text = _forward_fits_two_experts(one_chip,
+                                     _served(get_config("jamba2_3b")), 8, 1024)
+    assert re.search(r"^\s*%?mamba_scan[\w.]* = ", text, re.M)
+    assert re.search(r"^\s*%?flash_attention[\w.]* = ", text, re.M)
+
+
+def test_forward_carries_mixer_scopes():
+    """On the CPU: the lowered forward's op locations name the layer kind
+    (``jax.named_scope`` in ``transformer._apply_slot``), which device
+    traces carry as op metadata."""
+    from repro.configs import get_config, smoke_config
+    from repro.models import transformer
+    cfg = smoke_config(get_config("jamba2_3b"))
+    tokens = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    text = jax.jit(
+        lambda p, t: transformer.forward(p, t, cfg, mode="eval")[0]).lower(
+        transformer.abstract_params(cfg), tokens).as_text(debug_info=True)
+    for scope in ("mamba", "attention", "mlp"):
+        assert re.search(rf'loc\("(?:[^"]*/)?{scope}/', text), scope
+
+
+def test_starcoder2_forward_two_experts_fit_v5e(one_chip, monkeypatch):
+    """The served full-width forward (Pallas attention, bf16 weights)
+    compiles with native kernels, and two experts' weights plus its working
+    set fit one chip's HBM (the pool the chip smoke serves from)."""
+    import repro.kernels.ops as ops
+    from repro.configs import get_config
+
+    # the CPU process would pick interpret mode; compile the chip's branch
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    _forward_fits_two_experts(one_chip, _served(get_config("starcoder2_3b")),
+                              8, 64)
