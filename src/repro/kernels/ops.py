@@ -44,11 +44,10 @@ def decode_attention_op(q, k_cache, v_cache, pos, *, window=0, impl="pallas",
                             block_k=block_k, interpret=interpret_mode())
 
 
-def mamba_scan_op(x, dt, b_mat, c_mat, a, d_vec, *, impl="pallas",
-                  block_d=128, block_s=128):
-    """Returns (y [B,S,D], h_final [B,D,N])."""
+def mamba_scan_op(x, dt, b_mat, c_mat, a, d_vec, *, impl="pallas"):
+    """Returns (y [B,S,D], h_final [B,D,N]), tiled by the kernel's plan
+    (``mamba_scan_plan``)."""
     if impl == "xla":
         return ref.mamba_scan_ref(x, dt, b_mat, c_mat, a, d_vec)
     return mamba_scan(x, dt, b_mat, c_mat, a, d_vec,
-                      block_d=block_d, block_s=block_s,
                       interpret=interpret_mode())

@@ -156,8 +156,7 @@ def mamba_forward(params, x, cfg, compute_dtype=jnp.bfloat16, state=None):
 
     if cfg.attn_impl == "pallas" and s > 1 and state is None:
         from repro.kernels import mamba_scan_op
-        y, h_final = mamba_scan_op(x_c, dt, b_c, c_c, a,
-                                   params["D"], block_s=cfg.ssm_chunk)
+        y, h_final = mamba_scan_op(x_c, dt, b_c, c_c, a, params["D"])
     else:
         h0 = None if state is None else state["ssm"]
         y, h_final = chunked_scan(x_c, dt, b_c, c_c, a, params["D"],

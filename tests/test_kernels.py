@@ -10,7 +10,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import (flash_attention,
                                           flash_attention_plan)
-from repro.kernels.mamba_scan import mamba_scan
+from repro.kernels.mamba_scan import mamba_scan, mamba_scan_plan
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
@@ -240,6 +240,10 @@ def test_decode_attention_block_sweep():
     (1, 128, 128, 16, 64, 128),   # two sequence chunks
     (2, 256, 256, 16, 128, 128),  # two channel blocks
     (1, 64, 128, 8, 64, 64),      # narrow state / small blocks
+    # the plan's tiling: five 128-lane channel blocks, three 128-step lane
+    # tiles in one chunk, 84 padded steps
+    (1, 300, 640, 16, None, None),
+    (3, 300, 1280, 16, None, None),  # two rows a program, a remainder of one
 ])
 def test_mamba_scan_matches_ref(b, s, d, n, block_s, block_d, dtype):
     keys = jax.random.split(jax.random.PRNGKey(7), 6)
@@ -252,11 +256,41 @@ def test_mamba_scan_matches_ref(b, s, d, n, block_s, block_d, dtype):
     y, h = mamba_scan(x, dt, b_mat, c_mat, a, d_vec,
                       block_d=block_d, block_s=block_s, interpret=True)
     y_ref, h_ref = ref.mamba_scan_ref(x, dt, b_mat, c_mat, a, d_vec)
-    tol = _tol(dtype)
     np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(y_ref, np.float32), **tol)
-    np.testing.assert_allclose(np.asarray(h, np.float32),
-                               np.asarray(h_ref, np.float32), **tol)
+                               np.asarray(y_ref, np.float32), **_tol(dtype))
+    # the state is f32 from f32 copies of the same inputs, whatever x's dtype
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                               **_tol(jnp.float32))
+
+
+def test_mamba_scan_plan_folds_batch_rows():
+    """Narrow state tiles share a program: at d 1280 (no multiple of 384 or
+    512) the plan takes 256 channels (4 state vregs), folds two rows, and
+    batch 3 leaves one row in the last program."""
+    plan = mamba_scan_plan(3, 300, 1280, 16, jnp.float32, jnp.float32)
+    assert (plan.block_d, plan.batch_fold) == (256, 2)
+    assert plan.grid(3) == (2, 5, 1)
+    assert (plan.block_s, plan.s_pad, plan.lane_tile) == (384, 384, 128)
+
+
+@pytest.mark.parametrize("batch,s,d,io_dtype", [
+    (1, 1024, 5120, jnp.float32),      # jamba2_3b as served, each profiled
+    (2, 1024, 5120, jnp.float32),      # batch: x bf16, dt/B/C f32
+    (4, 1024, 5120, jnp.float32),
+    (8, 1024, 5120, jnp.float32),
+    (1, 256, 8192, jnp.bfloat16),      # falcon_mamba_7b d_inner
+])
+def test_mamba_scan_plan_served_shapes(batch, s, d, io_dtype):
+    """At the served shapes the state tile is lane-dense (whole 128-lane
+    groups dividing D, 8 f32 vregs at N 16), the lane tiles are whole, and
+    one program fits v5e's 16 MiB of scoped VMEM."""
+    plan = mamba_scan_plan(batch, s, d, 16, jnp.bfloat16, io_dtype)
+    assert plan.block_d % 128 == 0 and d % plan.block_d == 0
+    assert plan.n_state * plan.block_d == 8 * 8 * 128
+    assert plan.block_s % plan.lane_tile == 0 and plan.lane_tile == 128
+    assert plan.s_pad == s
+    assert plan.vmem_bytes() <= 16 * 2 ** 20
+    assert plan.grid(batch)[0] * plan.batch_fold >= batch
 
 
 def test_mamba_scan_state_carry_chunk_boundary():

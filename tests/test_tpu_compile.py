@@ -93,13 +93,13 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, h, hkv, s, window):
     assert "flash_attention" in compiled.as_text()
 
 
-@pytest.mark.parametrize("b,s,d,block_s,io_dtype", [
-    (1, 256, 8192, 128, jnp.bfloat16),      # falcon_mamba_7b d_inner
-    # jamba2_3b as served: d_inner 5120, the model's ssm_chunk of 256, x in
-    # bf16 and dt/B/C in f32 (the mixer's dtypes)
-    (8, 1024, 5120, 256, jnp.float32),
+@pytest.mark.parametrize("b,s,d,io_dtype", [
+    (1, 256, 8192, jnp.bfloat16),           # falcon_mamba_7b d_inner
+    # jamba2_3b as served: d_inner 5120, the plan's tiling, x in bf16 and
+    # dt/B/C in f32 (the mixer's dtypes)
+    (8, 1024, 5120, jnp.float32),
 ], ids=["falcon-mamba", "jamba2-served"])
-def test_mamba_scan_compiles_for_v5e(one_chip, b, s, d, block_s, io_dtype):
+def test_mamba_scan_compiles_for_v5e(one_chip, b, s, d, io_dtype):
     n = 16
     x = _shape(one_chip, (b, s, d), jnp.bfloat16)
     dt = _shape(one_chip, (b, s, d), io_dtype)
@@ -108,7 +108,7 @@ def test_mamba_scan_compiles_for_v5e(one_chip, b, s, d, block_s, io_dtype):
     d_vec = _shape(one_chip, (d,), jnp.float32)
     compiled = _native(
         lambda x, dt, bm, cm, a, dv: mamba_scan(
-            x, dt, bm, cm, a, dv, block_s=block_s, interpret=False),
+            x, dt, bm, cm, a, dv, interpret=False),
         x, dt, bc, bc, a, d_vec)
     assert "mamba_scan" in compiled.as_text()
 
