@@ -34,18 +34,6 @@ from repro.obs import log as obslog
 log = obslog.get_logger("bench")
 
 
-def _roofline(quick: bool = False):
-    from benchmarks import roofline
-    path = "dryrun_results.json"
-    if not os.path.exists(path):
-        return {"skipped": f"{path} not found — run "
-                "`python -m repro.launch.dryrun --sweep --both-meshes` first"}
-    rows = roofline.main(["--in", path, "--out", "roofline_report.json"])
-    return {"cells": len(rows),
-            "dominant": {d: sum(1 for r in rows if r["dominant"] == d)
-                         for d in ("compute", "memory", "collective")}}
-
-
 def _lint(quick: bool = False):
     """Invariant-analyzer cost + status (the CI `config` job summary row):
     wall time and violation count of `python -m repro.analysis --strict src`
@@ -75,7 +63,6 @@ SUITES_INFO = {
     "fig5_12": (bench_batch_latency.run,
                 "paper Fig. 5/12 batch-latency linearity"),
     "kernels": (bench_kernels.run, "Pallas kernels vs oracles"),
-    "roofline": (_roofline, "EXPERIMENTS.md roofline (needs dry-run sweep)"),
     "online": (bench_online.run,
                "online gateway throughput/p99 at fixed offered load"),
     "memory": (bench_memory.run,

@@ -70,10 +70,6 @@ ALLOWLIST: Tuple[Exemption, ...] = (
     Exemption("wallclock", "repro.fleet.search", "search_placement",
               "time_budget_s bounds search effort, never the result "
               "(the reported cost is an exact replay either way)"),
-    Exemption("wallclock", "repro.launch.dryrun", "_compile_stats",
-              "reports lower/compile wall time of the dry-run build"),
-    Exemption("wallclock", "repro.launch.train", "main",
-              "training throughput measurement (tokens/sec)"),
     Exemption("wallclock", "repro.analysis.__main__", "main",
               "the analyzer reports its own wall time; not sim semantics"),
     Exemption("wallclock", "repro.obs.tracer", "Tracer.clock",

@@ -1,11 +1,3 @@
-from repro.configs.base import (
-    ARCH_IDS,
-    SHAPES,
-    ShapeSpec,
-    applicable_shapes,
-    get_config,
-    smoke_config,
-)
+from repro.configs.base import ARCH_IDS, get_config, smoke_config
 
-__all__ = ["ARCH_IDS", "SHAPES", "ShapeSpec", "applicable_shapes",
-           "get_config", "smoke_config"]
+__all__ = ["ARCH_IDS", "get_config", "smoke_config"]

@@ -1,9 +1,8 @@
-"""Architecture registry + assigned input shapes + smoke-config reduction."""
+"""Architecture registry + smoke-config reduction."""
 from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Dict, List, Optional
 
 from repro.models.config import ModelConfig
 
@@ -24,45 +23,12 @@ ARCH_IDS = [
 _ALIASES = {a.replace("_", "-"): a for a in ARCH_IDS}
 
 
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES: Dict[str, ShapeSpec] = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
-
-
 def get_config(arch: str) -> ModelConfig:
     arch = _ALIASES.get(arch, arch)
     if arch not in ARCH_IDS:
         raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS}")
     mod = importlib.import_module(f"repro.configs.{arch}")
     return mod.CONFIG
-
-
-def applicable_shapes(cfg: ModelConfig) -> List[str]:
-    """Shape cells for an arch; long_500k only with sub-quadratic attention
-    (skips recorded in DESIGN.md SSArch-applicability)."""
-    shapes = ["train_4k", "prefill_32k", "decode_32k"]
-    if cfg.supports_long_context:
-        shapes.append("long_500k")
-    return shapes
-
-
-def shape_overrides(cfg: ModelConfig, shape: str) -> ModelConfig:
-    """Per-cell config adjustments (e.g. jamba attention switches to a 32k
-    sliding window for the 500k-context cell)."""
-    if shape == "long_500k" and cfg.family == "hybrid" and not cfg.sliding_window:
-        return dataclasses.replace(cfg, sliding_window=32768)
-    return cfg
 
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:

@@ -1,7 +1,7 @@
 """Qwen2-VL-2B [arXiv:2409.12191; hf]: VLM backbone with M-RoPE.
 
-The vision/patch frontend is a STUB: input_specs() provides M-RoPE position
-triples (and optional patch embeddings); the backbone is a GQA decoder with
+The vision/patch frontend is a STUB: callers pass M-RoPE position triples
+(``positions`` [3, B, S]); the backbone is a GQA decoder with
 3-section rotary (temporal/height/width = 16/24/24 over the 64-dim half)."""
 from repro.models.config import ModelConfig
 

@@ -1,7 +1,7 @@
 """Whisper-medium [arXiv:2212.04356]: encoder-decoder audio backbone.
 
-The conv/mel frontend is a STUB: input_specs() provides precomputed frame
-embeddings [B, 1500, d]. Deviations (DESIGN.md): vocab padded 51865 -> 51968
+The conv/mel frontend is a STUB: callers pass precomputed frame embeddings
+[B, 1500, d]. Deviations (DESIGN.md): vocab padded 51865 -> 51968
 for sharding (excess logits masked); sinusoidal positions on both stacks.
 """
 from repro.models.config import ModelConfig

@@ -102,21 +102,6 @@ class ModelConfig:
     def dt_rank(self) -> int:
         return self.ssm_dt_rank or max(1, math.ceil(self.d_model / 16))
 
-    @property
-    def is_attention_free(self) -> bool:
-        return self.family == "ssm"
-
-    @property
-    def supports_long_context(self) -> bool:
-        """Sub-quadratic decode: SSM/hybrid always; attention iff windowed."""
-        if self.family in ("ssm", "hybrid"):
-            return True
-        return self.sliding_window > 0
-
-    @property
-    def has_decode(self) -> bool:
-        return True  # every assigned arch has a decoder (whisper is enc-dec)
-
     def period(self) -> int:
         """Scan-period length: lcm of the structural periods."""
         p = 1
@@ -191,18 +176,6 @@ class ModelConfig:
             total += enc + xattn
         total += d  # final norm
         return total
-
-    def flops_per_token(self, seq_len: int, decode: bool = False) -> float:
-        """Model FLOPs per token: 6N (+attention term) train, 2N decode."""
-        n_active = self.param_count(active_only=True)
-        base = (2.0 if decode else 6.0) * n_active
-        # attention score FLOPs (per token, against seq_len context)
-        attn_ctx = min(seq_len, self.sliding_window) if self.sliding_window else seq_len
-        n_attn_layers = sum(1 for s in self.block_pattern() if s.mixer == "attn") \
-            * self.num_periods()
-        factor = 2.0 if decode else 6.0  # fwd only vs fwd+bwd
-        base += factor * 2 * n_attn_layers * self.num_heads * self.resolved_head_dim * attn_ctx
-        return base
 
 
 def _lcm(a: int, b: int) -> int:

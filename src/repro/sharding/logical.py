@@ -3,9 +3,9 @@
 MaxText-style indirection: model code annotates arrays with *logical* axis
 names ("batch", "heads", "mlp", ...); a rule table maps logical names to mesh
 axes. Resolution drops any mesh axis that does not evenly divide the dimension
-(e.g. 24 attention heads on a 16-way ``model`` axis, or 8 Mixtral experts),
-which keeps every (arch x shape x mesh) cell lowerable without per-arch
-special cases.
+(e.g. 24 attention heads on a 16-way ``model`` axis, or 8 Mixtral experts).
+Without an active table (``use_rules``), ``logical_constraint`` is the
+identity; the single-device serving path activates none.
 """
 from __future__ import annotations
 
@@ -19,98 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # logical axis -> ordered candidate mesh axes. Earlier axes are applied first;
 # each mesh axis may be used at most once per array.
 LogicalRules = Mapping[str, Tuple[str, ...]]
-
-# Training: FSDP on "data" (+"pod"), TP on "model", residual-stream sequence
-# parallelism on "model" (Megatron-SP style: the carry between blocks is
-# [batch/data, seq/model, d]; GSPMD inserts the gather/scatter pairs at the
-# projection boundaries where "mlp"/"heads"/"ssm_inner" take over the axis).
-TRAIN_RULES: LogicalRules = {
-    "batch": ("pod", "data"),
-    "embed": ("data",),          # FSDP shard of weight d_model dims
-    "embed_act": (),             # activation d_model stays replicated
-    "seq_q": ("model",),         # residual-stream sequence sharding
-    "seq_attn": (),              # attention-internal seq (heads take "model")
-    "heads": ("model",),
-    "kv_heads": ("model",),
-    "qkv": ("model",),           # fused q/kv projection output dim
-    "mlp": ("model",),
-    "moe_mlp": ("model",),
-    "experts": ("model",),
-    # MoE dispatch groups NEVER take the model axis: a model-sharded group
-    # dim competes with the expert-FFN f dim for the same axis, forcing GSPMD
-    # to replicate h and all-reduce FULL f32 expert grads (9.2 GiB/layer on
-    # mixtral — §Perf iteration B1). Groups shard (pod, data); f shards model
-    # (TP), or experts take model under true EP.
-    "moe_groups": ("pod", "data"),
-    "moe_tokens": (),                  # within-group token dim
-    "vocab": ("model",),
-    "kv_seq": (),
-    "ssm_inner": ("model",),
-    "ssm_state": (),
-    "conv": (),
-    "layers": (),
-    "stage": (),
-}
-
-# Serving/decode: TP on "model", batch on ("pod","data"); weights replicated
-# on the data axis by default (no FSDP gather in the decode loop) — the
-# per-arch rule builder re-enables FSDP when a 16-way TP shard exceeds HBM.
-# KV caches shard seq on whatever batch leaves free (long-context cells).
-SERVE_RULES: LogicalRules = {
-    **TRAIN_RULES,
-    "embed": (),
-    "seq_q": (),
-    "kv_seq": ("data", "model"),
-}
-
-
-def rules_for(cfg, mesh: Mesh, mode: str,
-              hbm_budget_bytes: float = 8e9) -> LogicalRules:
-    """Arch-aware rule table (divisibility quirks + memory-driven FSDP).
-
-    - serve: if a pure-TP (model-axis) bf16 weight shard would exceed
-      ``hbm_budget_bytes`` (mixtral-8x22b), weight d_model dims also shard on
-      "data" (FSDP-gathered serving).
-    """
-    rules = dict(TRAIN_RULES if mode == "train" else SERVE_RULES)
-    axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    model_n = axis_sizes.get("model", 1)
-    if mode != "train":
-        tp_bytes = cfg.param_count() * 2 / model_n
-        if tp_bytes > hbm_budget_bytes:
-            rules["embed"] = ("data",)
-    heads_split = cfg.num_heads and model_n > 1 and cfg.num_heads % model_n
-    if heads_split:
-        # heads don't divide the model axis (starcoder2/phi4 24H, qwen2 12H
-        # on 16): shard attention internals by the q-sequence instead
-        # (flash-style row parallelism; KV replicated on model is cheap for
-        # small-kv GQA) — §Perf iteration B7
-        rules["seq_attn"] = ("model",)
-    if mode == "prefill" and not cfg.moe_num_experts \
-            and cfg.family != "ssm":
-        # §Perf iteration B8: full sequence parallelism for prefill —
-        # residual seq-sharded, attention/MLP weights unsharded on the model
-        # axis, every matmul local; the per-layer KV all-gather (~tens of
-        # MB) replaces the TP reshard pair that dominated these cells
-        # (3.1–3.4x on the 24/12-head archs, 2.6x on whisper). Weights
-        # replicate when the bf16 model fits a chip, else FSDP on the data
-        # axis (B9: per-layer bf16 gather ~400 MB for minitron-8b, far
-        # below its TP reshard traffic). SSMs are excluded: the selective
-        # scan is sequential along seq and cannot seq-shard.
-        rules["seq_q"] = ("model",)
-        rules["seq_attn"] = ("model",)
-        rules["qkv"] = ()
-        rules["mlp"] = ()
-        rules["heads"] = ()
-        rules["kv_heads"] = ()
-        if cfg.param_count() * 2 >= 12e9:
-            rules["embed"] = ("data",)      # FSDP-gathered weights (B9)
-            rules["vocab"] = ()
-    if cfg.moe_num_experts and model_n > 1 \
-            and cfg.moe_num_experts % model_n == 0:
-        # true expert parallelism: experts own "model", groups own "data"
-        rules["moe_groups"] = ("pod", "data")
-    return rules
 
 
 class _RulesState(threading.local):
@@ -131,18 +39,6 @@ def use_rules(rules: Optional[LogicalRules], mesh: Optional[Mesh] = None):
         yield
     finally:
         _STATE.rules, _STATE.mesh = prev
-
-
-def current_rules() -> Optional[LogicalRules]:
-    return _STATE.rules
-
-
-def current_mesh() -> Optional[Mesh]:
-    if _STATE.mesh is not None:
-        return _STATE.mesh
-    # fall back to the ambient mesh context if one is installed
-    env = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    return _STATE.mesh or None
 
 
 def resolve_spec(

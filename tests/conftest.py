@@ -1,8 +1,8 @@
 import os
 import sys
 
-# smoke tests and benches must see ONE device (the dry-run sets its own
-# XLA_FLAGS before importing jax; never set device-count flags globally here)
+# smoke tests and benches must see ONE device: never set device-count flags
+# globally here
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import dataclasses  # noqa: E402
